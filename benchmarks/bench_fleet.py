@@ -52,16 +52,15 @@ def _config(k: int, batch: int, top: int) -> d4m.StreamConfig:
     )
 
 
-def _worker_env(cache_dir: str) -> dict:
-    """Pin each worker to one compute thread (the paper's one-core-per-
-    instance shape) and share one compilation cache across the fleet so
-    the N-th worker doesn't re-pay the first worker's compile."""
-    return {
-        "OMP_NUM_THREADS": "1",
-        "OPENBLAS_NUM_THREADS": "1",
-        "JAX_COMPILATION_CACHE_DIR": cache_dir,
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
-    }
+# Pin each worker to one compute thread (the paper's one-core-per-instance
+# shape).  Workers share the checkout's compilation cache
+# (repro.compile_cache), so the N-th worker doesn't re-pay the first
+# worker's compile.
+_WORKER_ENV = {
+    "OMP_NUM_THREADS": "1",
+    "OPENBLAS_NUM_THREADS": "1",
+    "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+}
 
 
 def _run_fleet(
@@ -110,7 +109,7 @@ def main(
     rates: dict[int, float] = {}
 
     with tempfile.TemporaryDirectory(prefix="bench-fleet-") as workdir:
-        env = _worker_env(os.path.join(workdir, "jax-cache"))
+        env = _WORKER_ENV
         # warmup fleet: populate the shared compilation cache so measured
         # legs time ingest, not XLA compiles
         _run_fleet(1, k, 4 * chunk, chunk, batch, scale, top, workdir, env)

@@ -37,6 +37,7 @@ import argparse
 import os
 import sys
 
+from repro import compile_cache
 from repro.bench.experiments import (  # noqa: F401  (SECTIONS re-exported)
     SECTIONS,
     ExperimentError,
@@ -98,6 +99,7 @@ def main() -> None:
     ap.add_argument("--json-dir", default=None,
                     help="directory for BENCH_<section>.json (default: cwd)")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.json_dir:
         os.environ["BENCH_JSON_DIR"] = args.json_dir
     try:

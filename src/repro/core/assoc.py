@@ -225,6 +225,52 @@ def from_triples(
 # internal: combine runs of equal keys in a sorted triple list, then compact
 # ---------------------------------------------------------------------------
 
+# The log-step passes below run as ``fori_loop``s over a shift of
+# ``d = 2**i``: one loop body per pass kind keeps the program (and XLA's TPU
+# compile time, which grows with program size) independent of the width.
+
+def _log_steps(n: int) -> int:
+    """Passes of a doubling scan over ``n`` elements (``d`` up to < n)."""
+    return max(0, n - 1).bit_length()
+
+
+def prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum by log-step shifted adds.  Same result as
+    ``jnp.cumsum`` on integers, but XLA's TPU compiler builds it in about
+    3 s where ``cumsum`` takes 12-20 s at every width, once per merge."""
+    idx = jnp.arange(x.shape[0], dtype=jnp.int32)
+
+    def step(i, x):
+        d = jnp.left_shift(1, i)
+        return x + jnp.where(idx >= d, jnp.roll(x, d), 0)
+
+    return lax.fori_loop(0, _log_steps(x.shape[0]), step, x)
+
+
+def run_fold(rows: jax.Array, cols: jax.Array, vals: jax.Array, add) -> jax.Array:
+    """Inclusive ``add``-fold over each run of equal (row, col) keys in
+    lexicographically sorted triples; a run's last element carries the
+    fold of the whole run.
+
+    Log-step shifted adds (Hillis-Steele): ``log2 n`` passes, each a
+    contiguous shift and a select.  Equal keys are contiguous, so "same key
+    as the element ``d`` back" means "same run".  (``lax.associative_scan``
+    is the textbook form, but its strided slices make XLA's TPU compiler
+    take minutes at a million entries; this compiles in seconds at any
+    width.)
+    """
+    idx = jnp.arange(rows.shape[0], dtype=jnp.int32)
+
+    def step(i, vals):
+        d = jnp.left_shift(1, i)
+        same = (
+            (rows == jnp.roll(rows, d)) & (cols == jnp.roll(cols, d)) & (idx >= d)
+        )
+        return jnp.where(same, add(jnp.roll(vals, d), vals), vals)
+
+    return lax.fori_loop(0, _log_steps(rows.shape[0]), step, vals)
+
+
 def _combine_sorted(
     rows: jax.Array, cols: jax.Array, vals: jax.Array, cap: int, sr: Semiring
 ) -> Assoc:
@@ -232,14 +278,7 @@ def _combine_sorted(
     ``sr.add`` and compact the survivors into a fresh Assoc of capacity
     ``cap``.  PAD-keyed slots are dropped."""
 
-    def comb(left, right):
-        lr, lc, lv = left
-        rr, rc, rv = right
-        same = (lr == rr) & (lc == rc)
-        return rr, rc, jnp.where(same, sr.add(lv, rv), rv)
-
-    # Segmented fold: associative because equal keys are contiguous (sorted).
-    _, _, acc = lax.associative_scan(comb, (rows, cols, vals))
+    acc = run_fold(rows, cols, vals, sr.add)
     nxt_r = jnp.concatenate([rows[1:], jnp.full((1,), -1, jnp.int32)])
     nxt_c = jnp.concatenate([cols[1:], jnp.full((1,), -1, jnp.int32)])
     is_end = (rows != nxt_r) | (cols != nxt_c)  # last element of each key-run
@@ -249,7 +288,7 @@ def _combine_sorted(
 
 def _compact(rows, cols, vals, keep, cap: int, sr: Semiring) -> Assoc:
     n_keep = keep.sum(dtype=jnp.int32)
-    pos = jnp.cumsum(keep, dtype=jnp.int32) - 1
+    pos = prefix_sum(keep.astype(jnp.int32)) - 1
     pos = jnp.where(keep, pos, cap)  # out-of-range -> dropped by mode="drop"
     out = empty(cap, sr, vals.dtype)
     out_rows = out.rows.at[pos].set(rows, mode="drop")
@@ -285,7 +324,9 @@ def lex_searchsorted(
     qc = jnp.asarray(qc, jnp.int32)
     lo = jnp.zeros(qr.shape, jnp.int32)
     hi = jnp.full(qr.shape, n, jnp.int32)
-    for _ in range(max(1, int(math.ceil(math.log2(max(n, 2)))) + 1)):
+
+    def step(_, lohi):
+        lo, hi = lohi
         mid = (lo + hi) >> 1
         mr = kr[mid]
         mc = kc[mid]
@@ -295,14 +336,34 @@ def lex_searchsorted(
             go_right = (mr < qr) | ((mr == qr) & (mc <= qc))
         # guard: once converged (lo == hi), clamped gathers must not move lo
         go_right = go_right & (mid < hi)
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, jnp.minimum(hi, mid))
-    return lo
+        return (
+            jnp.where(go_right, mid + 1, lo),
+            jnp.where(go_right, hi, jnp.minimum(hi, mid)),
+        )
+
+    rounds = max(1, int(math.ceil(math.log2(max(n, 2)))) + 1)
+    return lax.fori_loop(0, rounds, step, (lo, hi))[0]
 
 
 # ---------------------------------------------------------------------------
 # element-wise addition  (database union — the hierarchy's only required op)
 # ---------------------------------------------------------------------------
+
+def _head(x: Assoc, cap: int) -> Assoc:
+    """The first ``cap`` slots of ``x``: the only ones a merge into ``cap``
+    slots can keep.  Keys are sorted and unique, so the entry in slot ``i``
+    has ``i`` distinct keys before it in any merge; a live entry past the
+    head therefore means the merge overflows ``cap``, and is flagged so."""
+    if x.capacity <= cap:
+        return x
+    return Assoc(
+        rows=x.rows[:cap],
+        cols=x.cols[:cap],
+        vals=x.vals[:cap],
+        nnz=jnp.minimum(x.nnz, cap),
+        overflow=x.overflow | (x.rows[cap] != PAD),
+    )
+
 
 def add(a: Assoc, b: Assoc, cap: int | None = None, sr: Semiring = PLUS_TIMES) -> Assoc:
     """``C = A (+) B`` — element-wise semiring addition (table union).
@@ -310,10 +371,13 @@ def add(a: Assoc, b: Assoc, cap: int | None = None, sr: Semiring = PLUS_TIMES) -
     Both inputs are sorted, so we merge by rank (two lex-searchsorted passes)
     rather than re-sorting the concatenation: O((m+n) log(m+n)) comparisons
     with a small constant, and the exact algorithm the Pallas ``merge_add``
-    kernel implements in VMEM tiles on TPU.
+    kernel implements in VMEM tiles on TPU.  An input wider than ``cap``
+    enters by its first ``cap`` slots (:func:`_head`), so the cost follows
+    ``cap``: a snapshot of a 151M-slot top layer at ``cap`` 2M merges 2M.
     """
     if cap is None:
         cap = a.capacity + b.capacity
+    a, b = _head(a, cap), _head(b, cap)
     m, n = a.capacity, b.capacity
     total = m + n
     # merge-by-rank: stable positions for A's and B's elements in the merge.
@@ -323,12 +387,12 @@ def add(a: Assoc, b: Assoc, cap: int | None = None, sr: Semiring = PLUS_TIMES) -
     pos_b = jnp.arange(n, dtype=jnp.int32) + lex_searchsorted(
         a.rows, a.cols, b.rows, b.cols, side="right"
     )
-    rows = jnp.full((total,), PAD, jnp.int32)
-    cols = jnp.full((total,), PAD, jnp.int32)
-    vals = jnp.full((total,), sr.zero, a.vals.dtype)
-    rows = rows.at[pos_a].set(a.rows).at[pos_b].set(b.rows)
-    cols = cols.at[pos_a].set(a.cols).at[pos_b].set(b.cols)
-    vals = vals.at[pos_a].set(a.vals).at[pos_b].set(b.vals)
+    # the two rank vectors are a permutation of [0, total): one scatter per
+    # lane (XLA's TPU compile time grows with each scatter's width)
+    pos = jnp.concatenate([pos_a, pos_b])
+    rows = jnp.zeros((total,), jnp.int32).at[pos].set(jnp.concatenate([a.rows, b.rows]))
+    cols = jnp.zeros((total,), jnp.int32).at[pos].set(jnp.concatenate([a.cols, b.cols]))
+    vals = jnp.zeros((total,), a.vals.dtype).at[pos].set(jnp.concatenate([a.vals, b.vals]))
     out = _combine_sorted(rows, cols, vals, cap, sr)
     return dataclasses.replace(
         out, overflow=out.overflow | a.overflow | b.overflow

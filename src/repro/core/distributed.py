@@ -30,7 +30,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import assoc, hierarchical, multistream
-from ._compat import shard_map
 from .assoc import Assoc, PAD
 from .hierarchical import HierAssoc
 from .multistream import MultiStreamEngine
@@ -228,10 +227,11 @@ class ShardedAssoc:
         spec_batch = P(axis)
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(spec_state, spec_batch, spec_batch, spec_batch),
             out_specs=(spec_state, P()),
+            check_vma=False,
         )
         def _update(h, rows, cols, vals):
             h = jax.tree.map(lambda x: x[0], h)
@@ -255,10 +255,11 @@ class ShardedAssoc:
         self.update = jax.jit(_update, donate_argnums=(0,))
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(spec_state, P(), P()),
             out_specs=P(),
+            check_vma=False,
         )
         def _get(h, r, c):
             h = jax.tree.map(lambda x: x[0], h)

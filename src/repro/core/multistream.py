@@ -44,7 +44,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import assoc, hierarchical
-from ._compat import shard_map
 from .telemetry import TelemetrySnapshot
 from .assoc import Assoc, PAD
 from .hierarchical import HierAssoc
@@ -169,36 +168,26 @@ def cascades_per_instance(h: HierAssoc) -> jax.Array:
 
 
 def snapshot_packed(h: HierAssoc, cap: int, sr: Semiring = PLUS_TIMES) -> Assoc:
-    """Per-instance ``A = sum_i A_i``; an Assoc with leading ``[K]`` axis."""
-    return jax.vmap(lambda hi: hierarchical.snapshot(hi, cap=cap, sr=sr))(h)
+    """Per-instance ``A = sum_i A_i``; an Assoc with leading ``[K]`` axis.
+
+    A ``lax.map`` over the instances, not a ``vmap``: XLA's TPU compiler
+    takes minutes over batched merges at real widths and seconds over one
+    unbatched merge body, and snapshots are off the update path.
+    """
+    return lax.map(lambda hi: hierarchical.snapshot(hi, cap=cap, sr=sr), h)
 
 
 def merge_snapshots(snap: Assoc, cap: int, sr: Semiring = PLUS_TIMES) -> Assoc:
     """Fold a packed ``[K]``-leading snapshot into one global Assoc.
 
-    Pairwise (log-depth) semiring reduction: pad the instance axis to a power
-    of two with empty arrays, then halve with a vmapped ``assoc.add`` until a
-    single array remains.  With hash routing the instances hold disjoint key
-    subsets, so this is a pure disjoint union; the semiring add keeps it
-    correct for arbitrary (overlapping) packs too.
+    A left fold of ``assoc.add`` over the instance axis (one merge body,
+    compiled once; see :func:`snapshot_packed`).  With hash routing the
+    instances hold disjoint key subsets, so this is a pure disjoint union;
+    the semiring add keeps it correct for arbitrary (overlapping) packs too.
     """
-    k = snap.rows.shape[0]
-    p = 1 << max(0, (k - 1)).bit_length()
-    if p != k:
-        empty = assoc.empty(snap.rows.shape[1], sr, snap.vals.dtype)
-        filler = jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (p - k,) + x.shape), empty
-        )
-        snap = jax.tree.map(
-            lambda a, b: jnp.concatenate([a, b], axis=0), snap, filler
-        )
-    while p > 1:
-        half = p // 2
-        a = jax.tree.map(lambda x: x[:half], snap)
-        b = jax.tree.map(lambda x: x[half:], snap)
-        snap = jax.vmap(lambda x, y: assoc.add(x, y, cap=cap, sr=sr))(a, b)
-        p = half
-    return jax.tree.map(lambda x: x[0], snap)
+    acc = assoc.empty(cap, sr, snap.vals.dtype)
+    acc, _ = lax.scan(lambda a, s: (assoc.add(a, s, cap=cap, sr=sr), None), acc, snap)
+    return acc
 
 
 # ---------------------------------------------------------------------------
@@ -355,10 +344,11 @@ class MultiStreamEngine:
         self._state_spec = spec
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=spec,
+            check_vma=False,
         )
         def _update(h, rows, cols, vals):
             # local block: leaves [K, ...], triples [K, B] — no collectives.
@@ -370,10 +360,11 @@ class MultiStreamEngine:
         self.update = jax.jit(_update, donate_argnums=(0,))
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(spec,),
             out_specs=P(),
+            check_vma=False,
         )
         def _global_nnz(h):
             local = nnz_total(h)
@@ -391,7 +382,13 @@ class MultiStreamEngine:
         # calls hit the jit cache instead of retracing every time
         self._snapshot_fn = functools.lru_cache(maxsize=8)(
             lambda cap: jax.jit(
-                lambda hh: snapshot_packed(hh, cap=cap, sr=self.sr)
+                jax.shard_map(
+                    lambda hh: snapshot_packed(hh, cap=cap, sr=self.sr),
+                    mesh=mesh,
+                    in_specs=(spec,),
+                    out_specs=spec,
+                    check_vma=False,
+                )
             )
         )
         self._merge_fn = functools.lru_cache(maxsize=8)(

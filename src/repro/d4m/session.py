@@ -184,6 +184,26 @@ def _snapshot_packed(h: HierAssoc, cap: int, sr: Semiring, merge: bool):
     return multistream.merge_snapshots(per, cap=cap, sr=sr) if merge else per
 
 
+# the pallas engine's state is in the kernel's tiled layout; these convert
+# it (off the update path) for snapshots, checkpoints and restores
+def _tile(h: HierAssoc, sr: Semiring) -> HierAssoc:
+    from repro.kernels.hier_cascade import ops as cascade_ops
+
+    return cascade_ops.tile_state(h, sr)
+
+
+@functools.partial(jax.jit, static_argnames=("widths",))
+def _untile(h: HierAssoc, widths) -> HierAssoc:
+    from repro.kernels.hier_cascade import ops as cascade_ops
+
+    return cascade_ops.untile_state(h, widths)
+
+
+@functools.partial(jax.jit, static_argnames=("cap", "sr", "merge", "widths"))
+def _snapshot_tiled(h: HierAssoc, cap: int, sr: Semiring, merge: bool, widths):
+    return _snapshot_packed(_untile(h, widths), cap, sr, merge)
+
+
 # ---------------------------------------------------------------------------
 # the read side: immutable published views + the bound query namespace
 # ---------------------------------------------------------------------------
@@ -474,18 +494,22 @@ class D4MStream:
                 )
             )
         elif self.kind == "pallas":
+            from repro.kernels.common import next_pow2
             from repro.kernels.hier_cascade import ops as cascade_ops
 
             self.engine = None
             self.n_instances = self.k_per_device
             k = self.n_instances
             sr = self.sr
-            # interpret mode everywhere except the kernel's compile target;
-            # the compiled TPU leg is the ROADMAP's named next step
-            self._pallas_interpret = jax.default_backend() != "tpu"
+            # compiled on a TPU, interpreted elsewhere;
+            # a plan whose widest merge overflows VMEM fails here, not at
+            # the first step
+            cascade_ops.check_plan(self.plan.layer_caps, self.batch_size, self.dtype)
+            # the state lives in the kernel's tiled layout (see `state`);
+            # these are its pow2-padded [K, w] widths
+            self._widths = tuple(next_pow2(c) for c in self.plan.layer_caps)
             self._step = cascade_ops.build_step(
-                self.cuts, self.plan.layer_caps, sr, donate=True,
-                interpret=self._pallas_interpret,
+                self.cuts, self.plan.layer_caps, sr, donate=True, tiled=True
             )
             self._route = jax.jit(
                 lambda r, c, v: multistream.route_to_instances(
@@ -510,7 +534,10 @@ class D4MStream:
     # -- lifecycle -----------------------------------------------------------
     @property
     def state(self) -> HierAssoc:
-        """The live hierarchy pytree (allocated on first touch)."""
+        """The live hierarchy pytree (allocated on first touch), in the
+        engine's own layout: the ``pallas`` engine's layers are
+        ``[K, w / 128, 128]`` (:func:`~repro.kernels.hier_cascade.ops.tile_state`);
+        :meth:`flat_state` gives every engine's ``[K, w]`` form."""
         if self._state is None:
             self._state = self._init_state()
         return self._state
@@ -518,11 +545,18 @@ class D4MStream:
     @state.setter
     def state(self, value: HierAssoc) -> None:
         self._state = value
+
+    def flat_state(self) -> HierAssoc:
+        """The state with ``[.., w]`` layer lanes (a copy on ``pallas``)."""
+        if self.kind != "pallas":
+            return self.state
+        return _untile(self.state, self._widths)
+
     def _init_state(self) -> HierAssoc:
         if self.kind == "mesh":
             return self.engine.init_state()
         if self.kind in ("packed", "pallas"):
-            return multistream.init_packed(
+            h = multistream.init_packed(
                 self.n_instances,
                 self.cuts,
                 top_capacity=self.config.top_capacity,
@@ -533,6 +567,7 @@ class D4MStream:
                 # padded persistent buffers (hierarchical.pad_layers_pow2)
                 pad_pow2=(self.kind == "pallas"),
             )
+            return _tile(h, self.sr) if self.kind == "pallas" else h
         return hierarchical.init(
             self.cuts,
             top_capacity=self.config.top_capacity,
@@ -616,13 +651,10 @@ class D4MStream:
                     f"stream, got {rows.shape}"
                 )
             cuts, caps, sr = self.cuts, self.plan.layer_caps, self.sr
-            interpret = self._pallas_interpret
 
             def body(carry: HierAssoc, batch):
                 r, c, v = batch
-                nxt = cascade_ops.cascade_update(
-                    carry, r, c, v, cuts, caps, sr, interpret=interpret
-                )
+                nxt = cascade_ops.cascade_update_tiled(carry, r, c, v, cuts, caps, sr)
                 return nxt, multistream.nnz_per_instance(nxt)
 
             self.state, trace = lax.scan(body, self.state, (rows, cols, vals))
@@ -669,9 +701,14 @@ class D4MStream:
             if per_instance:
                 raise ValueError("single-instance session has no per-instance axis")
             snap = _snapshot_single(self.state, cap, self.sr)
-        elif self.kind in ("packed", "pallas"):
+        elif self.kind == "packed":
             snap = _snapshot_packed(
                 self.state, cap, self.sr, merge=not per_instance
+            )
+        elif self.kind == "pallas":
+            snap = _snapshot_tiled(
+                self.state, cap, self.sr, merge=not per_instance,
+                widths=self._widths,
             )
         else:
             snap = (
@@ -852,7 +889,7 @@ class D4MStream:
     def checkpoint(self, step: int, extra: Dict[str, Any] | None = None) -> None:
         """Async atomic save of the full hierarchy state (+ stream cursor
         metadata in ``extra``); overlaps serialization with compute."""
-        self._manager().save_async(step, self.state, extra=extra)
+        self._manager().save_async(step, self.flat_state(), extra=extra)
 
     def wait_checkpoint(self) -> None:
         self._manager().wait()
@@ -867,7 +904,7 @@ class D4MStream:
         :meth:`repro.checkpoint.manager.CheckpointManager.restore`."""
         mgr = self._manager()
         mgr.wait()
-        like = jax.tree.map(jnp.zeros_like, self.state)
+        like = jax.tree.map(jnp.zeros_like, self.flat_state())
         state, extra = mgr.restore(like, step=step, shardings=None,
                                    fallback=fallback)
         # The manager returns host (numpy) leaves.  They must come back as
@@ -888,6 +925,8 @@ class D4MStream:
             )
         else:
             state = jax.tree.map(lambda x: jnp.array(x, copy=True), state)
+        if self.kind == "pallas":
+            state = _tile(state, self.sr)
         self.state = state
         self._invalidate()
         return extra

@@ -13,6 +13,7 @@ from typing import Iterator, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @functools.partial(jax.jit, static_argnames=("n_edges", "scale", "a", "b", "c"))
@@ -38,6 +39,28 @@ def rmat_edges(
         dst_bit = (((r >= a) & (r < a + b)) | (r >= a + b + c)).astype(jnp.int32)
         src = src * 2 + src_bit
         dst = dst * 2 + dst_bit
+    return src, dst
+
+
+def rmat_edges_np(
+    rng: np.random.Generator,
+    n_edges: int,
+    scale: int,
+    a: float = 0.57,
+    b: float = 0.19,
+    c: float = 0.19,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Host-side :func:`rmat_edges`: the same quadrant sampling in numpy.
+
+    For load generators that must stay off the accelerator (a process that
+    touches JAX on a TPU host claims the chip).  Returns (src, dst) int32.
+    """
+    r = rng.random((scale, n_edges))
+    src_bits = r >= a + b  # quadrants c, d
+    dst_bits = ((r >= a) & (r < a + b)) | (r >= a + b + c)
+    weights = (1 << np.arange(scale - 1, -1, -1, dtype=np.int64))[:, None]
+    src = (src_bits * weights).sum(axis=0).astype(np.int32)
+    dst = (dst_bits * weights).sum(axis=0).astype(np.int32)
     return src, dst
 
 

@@ -255,6 +255,29 @@ class FleetReport:
         )
 
 
+def _check_one_tpu_worker(n_workers: int, env: Dict[str, str]) -> None:
+    """Refuse a fleet whose workers would contend for the TPU.
+
+    A worker that may use the TPU (libtpu installed and ``JAX_PLATFORMS``
+    not excluding it) claims every chip of the host when it starts, and
+    workers are not bound to a chip each, so a second such worker would
+    fail on libtpu's lock and be revived into a crash loop.  Decided from
+    the environment alone: the controller itself never touches JAX.
+    """
+    import importlib.util
+
+    platforms = [p.strip() for p in env.get("JAX_PLATFORMS", "").lower().split(",")]
+    if platforms != [""] and "tpu" not in platforms:
+        return
+    if n_workers > 1 and importlib.util.find_spec("libtpu") is not None:
+        raise ValueError(
+            f"n_workers={n_workers} TPU workers: each worker process claims "
+            f"every TPU chip of the host and workers are not bound to a chip "
+            f"each, so only one can start. Use n_workers=1, or set "
+            f"JAX_PLATFORMS=cpu in env= to run CPU workers."
+        )
+
+
 class FleetController:
     """Spawn and drive a fleet of ``n_workers`` subprocesses.
 
@@ -303,6 +326,7 @@ class FleetController:
         self.spawn_timeout_s = float(spawn_timeout_s)
         self.extra_env = dict(env or {})
         self.python = python
+        _check_one_tpu_worker(self.n_workers, {**os.environ, **self.extra_env})
         # Fault plan: consulted at controller sites (journal_disk_full) and
         # propagated to every worker via the environment, where it drives
         # the serve/checkpoint sites with only_worker scoping.  Explicit

@@ -287,6 +287,9 @@ def main(argv=None) -> int:
     ap.add_argument("--controller", required=True,
                     help="host:port of the controller's control listener")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+
+    compile_cache.enable()
     return run_worker(args.worker_id, args.controller)
 
 

@@ -17,7 +17,22 @@ from __future__ import annotations
 
 from typing import Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
+
+from repro.core import assoc
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Whether a Pallas call runs in interpret mode.
+
+    ``None`` (every kernel entry point's default) interprets only off the
+    TPU: there the kernels are compiled, and a kernel the compiler refuses
+    raises instead of silently falling back to the interpreter.
+    """
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 def lex_less(ar, ac, as_, br, bc, bs):
@@ -95,16 +110,7 @@ def run_combine(rows, cols, vals, add_fn) -> Tuple[jnp.ndarray, jnp.ndarray]:
     Returns ``(vals_scanned, is_run_end)`` — the run-end element carries the
     full ``add_fn``-fold of its run.  Shift-based: no gathers.
     """
-    n = rows.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    d = 1
-    while d < n:
-        pr = jnp.concatenate([rows[:d], rows[:-d]])
-        pc = jnp.concatenate([cols[:d], cols[:-d]])
-        pv = jnp.concatenate([vals[:d], vals[:-d]])
-        same = (rows == pr) & (cols == pc) & (idx >= d)
-        vals = jnp.where(same, add_fn(vals, pv), vals)
-        d *= 2
+    vals = assoc.run_fold(rows, cols, vals, lambda prev, cur: add_fn(cur, prev))
     nr = jnp.concatenate([rows[1:], rows[-1:] * 0 - 1])
     nc = jnp.concatenate([cols[1:], cols[-1:] * 0 - 1])
     is_end = (rows != nr) | (cols != nc)
@@ -128,9 +134,7 @@ def compact_monotone(lanes: Sequence[jnp.ndarray], keep: jnp.ndarray, fills):
     keep = keep.astype(jnp.bool_)
     dead = jnp.logical_not(keep).astype(jnp.int32)
     # exclusive prefix count of dead slots = how far each survivor travels
-    shift = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(dead)[:-1]]
-    )
+    shift = assoc.prefix_sum(dead) - dead
     cur = [jnp.where(keep, x, f) for x, f in zip(lanes, fills)]
     live = keep
     s = jnp.where(keep, shift, 0)

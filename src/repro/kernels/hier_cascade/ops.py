@@ -4,8 +4,14 @@ lane-skipping cascade kernel.
 ``cascade_update(h, rows, cols, vals, cuts, caps, sr)`` is the drop-in
 equivalent of ``multistream.packed_update`` for a pow2-padded packed
 hierarchy (``multistream.init_packed(..., pad_pow2=True)``): bit-identical
-snapshots / nnz / cascade counters / overflow flags, but per-step cost that
-tracks the lanes whose cuts actually fired instead of Σ layer capacities.
+snapshots / nnz / cascade counters / overflow flags.
+
+The session keeps its state in the kernel's own layout instead
+(:func:`tile_state`: each lane ``[w / 128, 128]``) and steps it with
+:func:`cascade_update_tiled`, so the layer buffers go into the kernel as
+they are and come out aliased in place: a step moves the batch and the
+layers whose cuts fired, not the whole state.  :func:`untile_state` gives
+the ``[K, w]`` layout back for snapshots and checkpoints.
 
 The batch is canonicalized *outside* the kernel with the exact
 ``assoc.from_triples`` the cond and branchless engines use, so all three
@@ -14,7 +20,7 @@ paths fold duplicate batch keys identically — that, plus the kernel's
 """
 from __future__ import annotations
 
-import functools
+import dataclasses
 from typing import Sequence, Tuple
 
 import jax
@@ -27,7 +33,15 @@ from repro.core.hierarchical import HierAssoc
 from repro.core.semiring import PLUS_TIMES, Semiring
 
 from .. import common
-from .kernel import hier_cascade_pallas
+from .kernel import check_fits, hier_cascade_pallas, tile, untile
+
+
+def check_plan(caps: Sequence[int], batch_size: int, dtype=jnp.float32) -> int:
+    """Refuse, with a ``ValueError``, a plan the kernel cannot hold: values
+    that are not 32-bit, or layers whose widest merge overflows the VMEM
+    budget.  Returns the VMEM bytes the kernel will claim."""
+    widths = [common.next_pow2(int(c)) for c in caps] + [common.next_pow2(batch_size)]
+    return check_fits(widths, dtype)
 
 
 def _pad_axis1(x, width, fill):
@@ -39,7 +53,35 @@ def _pad_axis1(x, width, fill):
     )
 
 
-def cascade_update(
+def tile_state(h: HierAssoc, sr: Semiring = PLUS_TIMES) -> HierAssoc:
+    """A pow2-padded packed hierarchy (``[K, w]`` lanes) in the kernel's
+    ``[K, w' / 128, 128]`` layout; counters are unchanged."""
+    layers = []
+    for l in h.layers:
+        w = l.rows.shape[1]
+        if w & (w - 1):
+            raise ValueError(
+                f"layer buffers must be pow2-padded (pad_layers_pow2), got width {w}"
+            )
+        zero = jnp.asarray(sr.zero, l.vals.dtype)
+        layers.append(dataclasses.replace(
+            l, rows=tile(l.rows, PAD), cols=tile(l.cols, PAD), vals=tile(l.vals, zero)
+        ))
+    return HierAssoc(layers=tuple(layers), cascades=h.cascades)
+
+
+def untile_state(h: HierAssoc, widths: Sequence[int]) -> HierAssoc:
+    """Inverse of :func:`tile_state` for layers of ``widths`` entries."""
+    layers = tuple(
+        dataclasses.replace(
+            l, rows=untile(l.rows, w), cols=untile(l.cols, w), vals=untile(l.vals, w)
+        )
+        for l, w in zip(h.layers, widths)
+    )
+    return HierAssoc(layers=layers, cascades=h.cascades)
+
+
+def cascade_update_tiled(
     h: HierAssoc,
     rows: jax.Array,  # [K, B] int32
     cols: jax.Array,
@@ -47,14 +89,12 @@ def cascade_update(
     cuts: Sequence[int],
     caps: Sequence[int],
     sr: Semiring = PLUS_TIMES,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> HierAssoc:
-    """One streaming update on every packed instance via the Pallas kernel.
-
-    ``h`` must be pow2-padded (``init_packed(pad_pow2=True)``); ``caps`` are
-    the true telescoped capacities (``hierarchical.telescoped_caps`` /
-    ``StreamConfig.plan().layer_caps``).
-    """
+    """One streaming update on every packed instance of a :func:`tile_state`
+    hierarchy; returns it in the same layout.  ``caps`` are the true
+    telescoped capacities (``hierarchical.telescoped_caps`` /
+    ``StreamConfig.plan().layer_caps``)."""
     cuts = tuple(int(c) for c in cuts)
     caps = tuple(int(c) for c in caps)
     b = rows.shape[1]
@@ -85,24 +125,50 @@ def cascade_update(
     return multistream.from_flat_layer_state(layers_o, nnz_o, casc_o, ov_o)
 
 
+def cascade_update(
+    h: HierAssoc,
+    rows: jax.Array,  # [K, B] int32
+    cols: jax.Array,
+    vals: jax.Array,
+    cuts: Sequence[int],
+    caps: Sequence[int],
+    sr: Semiring = PLUS_TIMES,
+    interpret: bool | None = None,
+) -> HierAssoc:
+    """One streaming update on every packed instance via the Pallas kernel.
+
+    ``h`` must be pow2-padded (``init_packed(pad_pow2=True)``) in the
+    ``[K, w]`` layout; it is tiled in and out around the kernel (the
+    session avoids that by keeping :func:`tile_state` state).
+    """
+    widths = [l.rows.shape[1] for l in h.layers]
+    out = cascade_update_tiled(
+        tile_state(h, sr), rows, cols, vals, cuts, caps, sr, interpret
+    )
+    return untile_state(out, widths)
+
+
 def build_step(
     cuts: Sequence[int],
     caps: Sequence[int],
     sr: Semiring = PLUS_TIMES,
     donate: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
+    tiled: bool = False,
 ):
-    """A jitted ``(h, rows, cols, vals) -> h`` kernel step.
+    """A jitted ``(h, rows, cols, vals) -> h`` kernel step, over ``[K, w]``
+    state or, with ``tiled``, over :func:`tile_state` state.
 
-    Donation keeps the (aliased) layer buffers in place across steps — with
-    ``input_output_aliases`` inside the kernel this makes the no-cascade path
-    a true in-place O(batch) update, no Σ-cap copies.
+    Tiled and donated, the layer buffers stay in place across steps — with
+    ``input_output_aliases`` inside the kernel the no-cascade path is an
+    in-place O(batch) update, no Σ-cap copies.
     """
     cuts = tuple(int(c) for c in cuts)
     caps = tuple(int(c) for c in caps)
+    update = cascade_update_tiled if tiled else cascade_update
 
     def step(h: HierAssoc, rows, cols, vals) -> HierAssoc:
-        return cascade_update(h, rows, cols, vals, cuts, caps, sr, interpret)
+        return update(h, rows, cols, vals, cuts, caps, sr, interpret)
 
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
@@ -115,7 +181,8 @@ def init_state(
     sr: Semiring = PLUS_TIMES,
     dtype=jnp.float32,
 ) -> Tuple[HierAssoc, Tuple[int, ...]]:
-    """Kernel-layout packed state + the true capacities to drive it with."""
+    """Pow2-padded packed state (``[K, w]`` lanes; :func:`tile_state` gives
+    the kernel's layout) + the true capacities to drive it with."""
     from repro.core.hierarchical import telescoped_caps
 
     caps = telescoped_caps(tuple(int(c) for c in cuts), top_capacity, batch_size)

@@ -80,7 +80,7 @@ def merge_add_pallas(
     b_cols,
     b_vals,
     sr: Semiring = PLUS_TIMES,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Run the merge kernel; returns (rows, cols, vals, keep) of length
     ``next_pow2(m + n)`` — sorted, run-combined, with the survivor mask.
@@ -115,5 +115,5 @@ def merge_add_pallas(
             pl.BlockSpec((total,), lambda: (0,)),
             pl.BlockSpec((total,), lambda: (0,)),
         ],
-        interpret=interpret,
+        interpret=common.resolve_interpret(interpret),
     )(a_rows, a_cols, a_vals, b_rows, b_cols, b_vals)

@@ -35,7 +35,7 @@ def merge_add(
     b: Assoc,
     cap: int | None = None,
     sr: Semiring = PLUS_TIMES,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Assoc:
     """``C = A (+) B`` via the Pallas bitonic-merge kernel."""
     if cap is None:

@@ -24,6 +24,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.assoc import PAD
 
+from .. import common
+
 
 def _scatter_add_kernel(ids_ref, rows_ref, table_ref, out_ref, *, block: int):
     # out_ref is aliased to table_ref's buffer; nothing to initialize.
@@ -31,9 +33,9 @@ def _scatter_add_kernel(ids_ref, rows_ref, table_ref, out_ref, *, block: int):
         tid = ids_ref[i]
 
         def apply(_):
-            row = pl.load(out_ref, (pl.ds(tid, 1), slice(None)))
+            row = out_ref[pl.ds(tid, 1), :]
             add = rows_ref[i, :][None, :].astype(row.dtype)
-            pl.store(out_ref, (pl.ds(tid, 1), slice(None)), row + add)
+            out_ref[pl.ds(tid, 1), :] = row + add
             return 0
 
         lax.cond(tid != PAD, apply, lambda _: 0, 0)
@@ -42,7 +44,7 @@ def _scatter_add_kernel(ids_ref, rows_ref, table_ref, out_ref, *, block: int):
     lax.fori_loop(0, block, body, 0)
 
 
-def scatter_add_pallas(ids, rows, table, interpret: bool = True):
+def scatter_add_pallas(ids, rows, table, interpret: bool | None = None):
     """``table[ids] += rows`` for live (non-PAD) ids; returns the new table.
 
     ids: int32[k] sorted-unique (PAD = dead slot); rows: [k, d]; table: [V, d].
@@ -60,5 +62,5 @@ def scatter_add_pallas(ids, rows, table, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         input_output_aliases={2: 0},
-        interpret=interpret,
+        interpret=common.resolve_interpret(interpret),
     )(ids, rows, table)

@@ -47,7 +47,7 @@ def _sort_dedup_kernel(
     keep_ref[...] = keep
 
 
-def sort_dedup_pallas(rows, cols, vals, sr: Semiring = PLUS_TIMES, interpret: bool = True):
+def sort_dedup_pallas(rows, cols, vals, sr: Semiring = PLUS_TIMES, interpret: bool | None = None):
     """Sort + combine a power-of-two triple batch.  Returns
     ``(rows, cols, vals, keep)`` sorted with run-combined values."""
     n = rows.shape[0]
@@ -64,5 +64,5 @@ def sort_dedup_pallas(rows, cols, vals, sr: Semiring = PLUS_TIMES, interpret: bo
         out_shape=out_shape,
         in_specs=[pl.BlockSpec((n,), lambda: (0,))] * 3,
         out_specs=[pl.BlockSpec((n,), lambda: (0,))] * 4,
-        interpret=interpret,
+        interpret=common.resolve_interpret(interpret),
     )(rows, cols, vals)

@@ -22,7 +22,7 @@ def from_triples(
     cap: int,
     sr: Semiring = PLUS_TIMES,
     valid=None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Assoc:
     rows = rows.astype(jnp.int32)
     cols = cols.astype(jnp.int32)

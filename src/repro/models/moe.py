@@ -244,8 +244,6 @@ def apply_moe_shardmap(p: Params, cfg: ModelConfig, x: jax.Array, ep_axis: str):
     """shard_map-EP MoE; requires EP_CONTEXT set by the launcher."""
     import functools
 
-    from repro.core._compat import shard_map
-
     mesh = EP_CONTEXT["mesh"]
     dp = EP_CONTEXT["dp"]
     m = cfg.moe
@@ -254,11 +252,12 @@ def apply_moe_shardmap(p: Params, cfg: ModelConfig, x: jax.Array, ep_axis: str):
     spec_e = P(ep_axis, None, None)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec_x, P(None, None), (P(None) if m.router_aux_free else P()),
                   spec_e, spec_e, spec_e),
         out_specs=(spec_x, P(ep_axis), P()),
+        check_vma=False,
     )
     def run(x_l, router, rbias, wg, wu, wd):
         Bl, Sl, dl = x_l.shape

@@ -470,8 +470,9 @@ class FileTailSource(Source):
 class RMATSource(Source):
     """Graph500-style power-law edge traffic (paper Section IV's workload).
 
-    Generates ``total_records`` edges in ``chunk_records`` groups with
-    :func:`repro.data.rmat.rmat_edges` (deterministic in ``seed``).
+    Generates ``total_records`` edges in ``chunk_records`` groups on the
+    host with :func:`repro.data.rmat.rmat_edges_np` (deterministic in
+    ``seed``), so the process that iterates it never touches the device.
     ``pregenerate=True`` materializes every chunk on the host up front so a
     serving benchmark measures the feed loop, not the generator;
     ``throttle_s`` sleeps between chunks to emulate a paced producer.
@@ -481,11 +482,9 @@ class RMATSource(Source):
     ``total_records`` stream, starting at chunk ``part`` — so N workers
     constructed with identical ``(total_records, chunk_records, scale,
     seed)`` and ``part = 0..N-1`` draw disjoint deterministic slices whose
-    union is exactly the single-source stream, bit for bit.  The key chain
-    advances per *global* chunk (skipped chunks still split the key), which
-    is what keeps ``num_parts=1`` identical to the historical stream and
-    kills the duplicate-traffic footgun of two sources sharing default
-    seeds.
+    union is exactly the single-source stream, bit for bit.  Each chunk's
+    generator is seeded by ``(seed, global chunk index)``, which is what
+    keeps ``num_parts=1`` identical to the unpartitioned stream.
     """
 
     def __init__(
@@ -522,24 +521,17 @@ class RMATSource(Source):
             self._pre = list(self._generate())
 
     def _generate(self) -> Iterator[Chunk]:
-        import jax
-
         from repro.data import rmat
 
-        key = jax.random.PRNGKey(self.seed)
         remaining = self.total_records
         chunk_index = 0
         while remaining > 0:
-            key, sub = jax.random.split(key)
             n = min(self.chunk_records, remaining)
             if chunk_index % self.num_parts == self.part:
-                # fixed-size generation (jit cache) then host-side trim
-                s, d = rmat.rmat_edges(sub, self.chunk_records, self.scale)
-                yield (
-                    np.asarray(s[:n], np.int32),
-                    np.asarray(d[:n], np.int32),
-                    np.ones((n,), np.float32),
-                )
+                # one generator per *global* chunk: partition-independent
+                rng = np.random.default_rng([self.seed, chunk_index])
+                s, d = rmat.rmat_edges_np(rng, n, self.scale)
+                yield s, d, np.ones((n,), np.float32)
             remaining -= n
             chunk_index += 1
 

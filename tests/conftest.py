@@ -14,8 +14,12 @@ import jax
 import numpy as np
 import pytest
 
-# persistent compilation cache: repeated pytest runs skip recompiles
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+from repro import compile_cache
+
+# persistent compilation cache (JAX_COMPILATION_CACHE_DIR, else the
+# checkout's .jax_cache/): repeated pytest runs and the fleet workers the
+# tests spawn skip recompiles
+compile_cache.enable()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 
 

@@ -189,3 +189,22 @@ def test_lex_searchsorted_matches_numpy():
         )
         want = np.searchsorted(keys, q, side=side)
         np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("cap", [8, 24, 40, 200])
+def test_add_into_narrower_cap_matches_full_merge(cap):
+    """Inputs wider than ``cap`` merge by their heads; the result (entries,
+    nnz, overflow flag) is the full-width merge cut to ``cap``."""
+    a, _ = mk(3, 48, cap=64)
+    b, _ = mk(4, 48, cap=64)
+    full = assoc.add(a, b, cap=128)
+    got = assoc.add(a, b, cap=cap)
+    n_full = int(full.nnz)
+    keep = min(cap, 128)
+    for f in ("rows", "cols", "vals"):
+        want = np.asarray(getattr(full, f))[:keep]
+        if cap > 128:
+            want = np.concatenate([want, np.asarray(getattr(assoc.empty(cap - 128), f))])
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)), want)
+    assert int(got.nnz) == min(n_full, cap)
+    assert bool(got.overflow) == (n_full > cap)

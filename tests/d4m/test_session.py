@@ -6,6 +6,7 @@ triples, same telemetry — on every engine (single lax.cond at K=1,
 vmap-packed at K>1, shard_map mesh at D>1), plus facade plumbing
 (ingest routing, query namespace, checkpoint/restore, stream scan).
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -306,3 +307,78 @@ def test_overflow_surfaces_in_telemetry():
     sess.update(ks + 100, ks, jnp.ones((32,)))
     assert sess.overflowed()
     assert sess.telemetry()["overflowed"]
+
+
+# ---------------------------------------------------------------- TPU rules
+def _on_tpu(monkeypatch):
+    """Steer backend-dependent choices as a TPU host would see them (no
+    device is touched: nothing here runs a step)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_auto_engine_on_tpu_is_the_compiled_kernel(monkeypatch):
+    _on_tpu(monkeypatch)
+    sess = d4m.D4MStream(
+        d4m.StreamConfig(cuts=(8, 32), top_capacity=256, batch_size=16,
+                         instances_per_device=4)
+    )
+    assert sess.kind == "pallas"
+
+
+@pytest.mark.parametrize("engine", ["pallas", "auto"])
+def test_pallas_over_vmem_budget_raises_on_tpu(monkeypatch, engine):
+    from repro.kernels.hier_cascade import kernel as cascade_kernel
+
+    _on_tpu(monkeypatch)
+    # a top layer wider than any merge the kernel's VMEM can hold
+    cfg = d4m.StreamConfig(
+        cuts=(1024, 8192), top_capacity=1 << 23, batch_size=512,
+        instances_per_device=4, engine=engine,
+    )
+    with pytest.raises(ValueError, match="MiB of VMEM") as err:
+        d4m.D4MStream(cfg)
+    assert f"{cascade_kernel.VMEM_BUDGET_BYTES / 2**20:.0f} MiB limit" in str(err.value)
+
+
+def test_pallas_needs_32bit_values(monkeypatch):
+    _on_tpu(monkeypatch)
+    with pytest.raises(ValueError, match="32-bit"):
+        d4m.D4MStream(d4m.StreamConfig(
+            cuts=(8,), top_capacity=64, batch_size=8,
+            instances_per_device=2, engine="pallas", dtype="bfloat16",
+        ))
+
+
+def test_pallas_state_stays_tiled_and_checkpoints_flat(tmp_path):
+    """The pallas engine keeps its layers in the kernel's [K, w/128, 128]
+    layout across steps; checkpoints hold the [K, w] form the packed engine
+    uses, and a restore tiles it back."""
+    cfg = d4m.StreamConfig(
+        cuts=(8, 32), top_capacity=256, batch_size=16,
+        instances_per_device=2, engine="pallas",
+    )
+    sess = d4m.D4MStream(cfg, checkpoint_dir=str(tmp_path))
+    packed = d4m.D4MStream(dataclasses.replace(cfg, engine="packed"))
+    rng = np.random.default_rng(3)
+    for _ in range(6):
+        r = jnp.asarray(rng.integers(0, 48, 16), jnp.int32)
+        c = jnp.asarray(rng.integers(0, 48, 16), jnp.int32)
+        v = jnp.ones((16,), jnp.float32)
+        sess.ingest(r, c, v)
+        packed.ingest(r, c, v)
+    assert all(l.rows.ndim == 3 and l.rows.shape[2] == 128 for l in sess.state.layers)
+    flat = sess.flat_state()
+    for lf, lp in zip(flat.layers, packed.state.layers):
+        n = lp.rows.shape[1]
+        np.testing.assert_array_equal(np.asarray(lf.rows)[:, :n], np.asarray(lp.rows))
+        np.testing.assert_array_equal(np.asarray(lf.vals)[:, :n], np.asarray(lp.vals))
+    sess.checkpoint(6, extra={"cursor": 6})
+    sess.wait_checkpoint()
+    fresh = d4m.D4MStream(cfg, checkpoint_dir=str(tmp_path))
+    assert fresh.restore()["cursor"] == 6
+    for a, b in zip(jax.tree.leaves(fresh.state), jax.tree.leaves(sess.state)):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    A, B = fresh.snapshot(cap=512), packed.snapshot(cap=512)
+    np.testing.assert_array_equal(np.asarray(A.rows), np.asarray(B.rows))
+    np.testing.assert_array_equal(np.asarray(A.vals), np.asarray(B.vals))

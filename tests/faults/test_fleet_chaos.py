@@ -29,7 +29,6 @@ CHUNK = 256
 CAP = 8192
 
 _ENV = {
-    "JAX_COMPILATION_CACHE_DIR": "/tmp/jax_cache",
     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     "OMP_NUM_THREADS": "1",
     "OPENBLAS_NUM_THREADS": "1",

@@ -25,11 +25,11 @@ TOTAL = 2048
 CHUNK = 256
 CAP = 8192
 
-# Workers are fresh processes: share the suite's persistent compilation
-# cache (conftest sets the same dir in-process) and pin BLAS threads, or a
-# 1-core CI box spends the whole drain window compiling 4x concurrently.
+# Workers are fresh processes: they share the suite's persistent compilation
+# cache (repro.compile_cache picks the same dir as conftest) and pin BLAS
+# threads, or a 1-core CI box spends the whole drain window compiling 4x
+# concurrently.
 _ENV = {
-    "JAX_COMPILATION_CACHE_DIR": "/tmp/jax_cache",
     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     "OMP_NUM_THREADS": "1",
     "OPENBLAS_NUM_THREADS": "1",
@@ -173,3 +173,24 @@ def test_fleet_worker_error_surfaces(tmp_path):
         ctl.kill_worker(0)
         with pytest.raises(RuntimeError, match="worker 0 died"):
             ctl.poll_workers()
+
+
+def test_fleet_refuses_more_than_one_tpu_worker(tmp_path, monkeypatch):
+    """Workers are not bound to a chip each: on a TPU host a second worker
+    would die on libtpu's lock and crash-loop, so the fleet refuses to
+    start it — without the controller touching JAX."""
+    import importlib.util
+
+    real = importlib.util.find_spec
+    monkeypatch.setattr(
+        importlib.util, "find_spec",
+        lambda name, *a: object() if name == "libtpu" else real(name, *a),
+    )
+    tpu_env = {"JAX_PLATFORMS": ""}
+    with pytest.raises(ValueError, match="TPU workers"):
+        FleetController(_config(), n_workers=2, workdir=str(tmp_path), env=tpu_env)
+    # one TPU worker, or CPU workers, are fine
+    FleetController(_config(), n_workers=1, workdir=str(tmp_path), env=tpu_env).close()
+    FleetController(
+        _config(), n_workers=2, workdir=str(tmp_path), env={"JAX_PLATFORMS": "cpu"}
+    ).close()

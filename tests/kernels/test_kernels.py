@@ -186,3 +186,14 @@ def test_run_combine_is_exact_inclusive_fold():
     v = jnp.asarray([1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 8.0])
     vals, is_end = common.run_combine(r, c, v, lambda x, y: x + y)
     np.testing.assert_allclose(np.asarray(vals)[np.asarray(is_end)], [6.0, 9.0, 6.0, 15.0])
+
+
+# ------------------------------------------------------- interpret default
+def test_interpret_default_follows_the_backend(monkeypatch):
+    """Kernels are interpreted only off the TPU; an explicit choice wins."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert common.resolve_interpret(None) is False
+    assert common.resolve_interpret(True) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert common.resolve_interpret(None) is True
+    assert common.resolve_interpret(False) is False

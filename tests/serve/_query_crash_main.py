@@ -11,7 +11,7 @@ Must run in its own interpreter: os._exit would kill the test process.
 """
 import sys
 
-from repro import d4m, serve
+from repro import compile_cache, d4m, serve
 from repro.faults import FaultPlan, Trigger
 
 # mirrors the test module's constants — both sides must agree so the
@@ -22,6 +22,7 @@ CRASH_AFTER_BATCHES = 12
 
 
 def main():
+    compile_cache.enable()
     ckpt_dir = sys.argv[1]
     sess = d4m.D4MStream(
         d4m.StreamConfig(

@@ -633,7 +633,6 @@ def test_crash_mid_query_then_restore_replay_requery(tmp_path):
     env["PYTHONPATH"] = os.pathsep.join(
         [str(src_root)] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
     )
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     helper = Path(__file__).with_name("_query_crash_main.py")
     child = subprocess.Popen(
