@@ -163,14 +163,18 @@ def update(
     cuts = tuple(int(c) for c in cuts)
     layers = list(h.layers)
     cascades = h.cascades
-    layers[0] = assoc.add(layers[0], batch, cap=layers[0].capacity, sr=sr)
+    # named scopes (op metadata only) name each phase in a profiler trace
+    with jax.named_scope("d4m.insert"):
+        layers[0] = assoc.add(layers[0], batch, cap=layers[0].capacity, sr=sr)
     for i, cut in enumerate(cuts):
         src, dst = layers[i], layers[i + 1]
         pred = src.nnz > cut
 
-        def do_cascade(src=src, dst=dst, sr=sr):
-            merged = assoc.add(dst, src, cap=dst.capacity, sr=sr)
-            cleared = assoc.empty(src.capacity, sr, src.vals.dtype)
+        def do_cascade(src=src, dst=dst, sr=sr, i=i):
+            # layer i + 1 (1-based) merged into layer i + 2
+            with jax.named_scope(f"d4m.merge.l{i + 1}"):
+                merged = assoc.add(dst, src, cap=dst.capacity, sr=sr)
+                cleared = assoc.empty(src.capacity, sr, src.vals.dtype)
             return merged, cleared
 
         def no_cascade(src=src, dst=dst):
@@ -200,7 +204,8 @@ def update_triples(
     branchless: bool = False,
 ) -> HierAssoc:
     """Ingest a raw triple batch (sorts/combines it, then :func:`update`)."""
-    batch = assoc.from_triples(rows, cols, vals, cap=rows.shape[0], sr=sr, valid=valid)
+    with jax.named_scope("d4m.batch"):
+        batch = assoc.from_triples(rows, cols, vals, cap=rows.shape[0], sr=sr, valid=valid)
     return update(h, batch, cuts, sr, branchless=branchless)
 
 

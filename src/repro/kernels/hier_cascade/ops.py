@@ -98,31 +98,34 @@ def cascade_update_tiled(
     cuts = tuple(int(c) for c in cuts)
     caps = tuple(int(c) for c in caps)
     b = rows.shape[1]
-    # same canonicalization as update_triples: sort + fold duplicates
-    batch = jax.vmap(
-        lambda r, c, v: assoc_mod.from_triples(r, c, v, cap=b, sr=sr)
-    )(rows, cols, vals)
-    qb = common.next_pow2(b)
-    batch_bufs = (
-        _pad_axis1(batch.rows, qb, PAD),
-        _pad_axis1(batch.cols, qb, PAD),
-        _pad_axis1(batch.vals, qb, jnp.asarray(sr.zero, batch.vals.dtype)),
-    )
-    layer_bufs, nnz, cascades, overflow = multistream.flat_layer_state(h)
-    # a malformed batch surfaces on layer 1 exactly as assoc.add would
-    overflow = overflow.at[:, 0].set(overflow[:, 0] | batch.overflow)
-    nnz_o, casc_o, ov_o, layers_o = hier_cascade_pallas(
-        batch_bufs,
-        nnz,
-        cascades,
-        overflow,
-        layer_bufs,
-        cuts=cuts,
-        caps=caps,
-        sr=sr,
-        interpret=interpret,
-    )
-    return multistream.from_flat_layer_state(layers_o, nnz_o, casc_o, ov_o)
+    # named scopes (op metadata only) name each phase in a profiler trace
+    with jax.named_scope("d4m.batch"):
+        # same canonicalization as update_triples: sort + fold duplicates
+        batch = jax.vmap(
+            lambda r, c, v: assoc_mod.from_triples(r, c, v, cap=b, sr=sr)
+        )(rows, cols, vals)
+        qb = common.next_pow2(b)
+        batch_bufs = (
+            _pad_axis1(batch.rows, qb, PAD),
+            _pad_axis1(batch.cols, qb, PAD),
+            _pad_axis1(batch.vals, qb, jnp.asarray(sr.zero, batch.vals.dtype)),
+        )
+    with jax.named_scope("d4m.cascade"):
+        layer_bufs, nnz, cascades, overflow = multistream.flat_layer_state(h)
+        # a malformed batch surfaces on layer 1 exactly as assoc.add would
+        overflow = overflow.at[:, 0].set(overflow[:, 0] | batch.overflow)
+        nnz_o, casc_o, ov_o, layers_o = hier_cascade_pallas(
+            batch_bufs,
+            nnz,
+            cascades,
+            overflow,
+            layer_bufs,
+            cuts=cuts,
+            caps=caps,
+            sr=sr,
+            interpret=interpret,
+        )
+        return multistream.from_flat_layer_state(layers_o, nnz_o, casc_o, ov_o)
 
 
 def cascade_update(

@@ -2,7 +2,8 @@
 
 Low-overhead metrics (counters / gauges / log-bucketed latency
 histograms), a bounded trace ring, and the enable plumbing shared by the
-serve and fleet stacks.  Off by default: sites hold ``None`` and cost one
+serve and fleet stacks, and span sites that put each host stage on the
+profiler's clock.  Off by default: sites hold ``None`` and cost one
 ``is not None`` check (the ``repro.faults`` zero-overhead contract).
 Enable with ``ServeConfig(metrics=True)`` or ``REPRO_OBS=1``.
 """
@@ -25,7 +26,7 @@ from repro.obs.registry import (
     dump_to_prometheus,
     env_enabled,
 )
-from repro.obs.trace import TraceRing, jax_profile
+from repro.obs.trace import NO_SPAN, SpanSite, TraceRing, Tracer, jax_profile
 
 __all__ = [
     "NUM_BUCKETS",
@@ -43,6 +44,9 @@ __all__ = [
     "MetricsRegistry",
     "dump_to_prometheus",
     "env_enabled",
+    "NO_SPAN",
+    "SpanSite",
     "TraceRing",
+    "Tracer",
     "jax_profile",
 ]

@@ -38,6 +38,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from repro.core.assoc import PAD
+from repro.obs import NO_SPAN
 
 # the device router's own hash constants: a retune of multistream.instance_of
 # reaches the host mirror mechanically, not via a parity-test failure
@@ -124,7 +125,7 @@ class MicrobatchRouter:
         backpressure: str = "block",
         zero: float = 0.0,
         val_dtype=np.float32,
-        metrics=None,
+        tracer=None,
     ):
         if n_instances is not None and n_instances < 1:
             raise ValueError(f"n_instances must be >= 1, got {n_instances}")
@@ -159,15 +160,17 @@ class MicrobatchRouter:
         self.routing_dropped = 0  # slot-overflow drops (0 by construction
         #                           while max_batch <= slot_cap)
         self.blocked_events = 0  # producer stalls under the "block" policy
-        # observability (repro.obs): handles are resolved ONCE here, so
+        # observability (repro.obs.Tracer): sites are resolved ONCE here, so
         # every hot-path site below is a single `is not None` check when
         # metrics are off — the faults-plane zero-overhead contract
-        if metrics is None:
-            self._h_flush = self._h_wait = self._g_depth = None
+        if tracer is None:
+            self._sp_route = self._sp_blocked = self._g_depth = None
         else:
-            self._h_flush = metrics.histogram("router.flush_ns")
-            self._h_wait = metrics.histogram("router.enqueue_wait_ns")
-            self._g_depth = metrics.gauge("router.queue_depth")
+            self._sp_route = tracer.site("router.route", "router.flush_ns")
+            self._sp_blocked = tracer.site("router.blocked", "router.enqueue_wait_ns")
+            self._g_depth = tracer.registry.gauge("router.queue_depth")
+        # slots of one routed batch: what its live records are padded to
+        self._slots = self.max_batch if n_instances is None else n_instances * self.slot_cap
 
     # -- producer side -------------------------------------------------------
     def push(self, rows: np.ndarray, cols: np.ndarray, vals: np.ndarray) -> None:
@@ -270,16 +273,16 @@ class MicrobatchRouter:
 
     # -- internals -----------------------------------------------------------
     def _flush_locked(self, partial: bool) -> None:
-        if self._h_flush is None:
-            self._flush_impl(partial)
-            return
-        t0 = time.perf_counter_ns()
-        try:
-            self._flush_impl(partial)
-        finally:
-            self._h_flush.record(time.perf_counter_ns() - t0)
+        # span: pad-assemble plus route into [K, slot_cap]; the enqueue that
+        # may block is the router.blocked span of its own
+        sp = self._sp_route
+        span = sp(batch=self.batches_out, slots=self._slots) if sp is not None else NO_SPAN
+        with span:
+            item = self._assemble(partial)
+            span.note(live=int(item[3]))
+        self._enqueue(item)
 
-    def _flush_impl(self, partial: bool) -> None:
+    def _assemble(self, partial: bool):
         take = self.max_batch if not partial else min(self._pend_count, self.max_batch)
         rows = np.full((self.max_batch,), PAD, np.int32)
         cols = np.full((self.max_batch,), PAD, np.int32)
@@ -299,14 +302,12 @@ class MicrobatchRouter:
         self._pend_count -= take
         self._oldest_ts = time.monotonic() if self._pend_count else None
         if self.n_instances is None:
-            item = (rows, cols, vals, take)
-        else:
-            br, bc, bv, rdrop = route_numpy(
-                rows, cols, vals, self.n_instances, self.slot_cap, self.zero
-            )
-            self.routing_dropped += rdrop
-            item = (br, bc, bv, take - rdrop)
-        self._enqueue(item)
+            return (rows, cols, vals, take)
+        br, bc, bv, rdrop = route_numpy(
+            rows, cols, vals, self.n_instances, self.slot_cap, self.zero
+        )
+        self.routing_dropped += rdrop
+        return (br, bc, bv, take - rdrop)
 
     def _enqueue(self, item) -> None:
         try:
@@ -317,12 +318,9 @@ class MicrobatchRouter:
                 self.dropped_records += int(item[3])
                 return
             self.blocked_events += 1
-            if self._h_wait is None:
+            sp = self._sp_blocked
+            with (sp(batch=self.batches_out) if sp is not None else NO_SPAN):
                 self._q.put(item)  # lossless: stall the producer
-            else:
-                t0 = time.perf_counter_ns()
-                self._q.put(item)
-                self._h_wait.record(time.perf_counter_ns() - t0)
         self.batches_out += 1
         self.records_out += int(item[3])
         if self._g_depth is not None:

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core.telemetry import TelemetrySnapshot
 from repro.d4m.config import ServeConfig
+from repro.obs import NO_SPAN
 
 from .router import DRAIN, MicrobatchRouter
 from .sources import Source
@@ -93,24 +94,29 @@ class D4MServer:
         # (True arms, False forces off), otherwise the REPRO_OBS environment
         # variable (how fleet workers inherit the controller's choice).  Off
         # means every site below holds None and costs one `is not None`.
-        from repro.obs import MetricsRegistry, TraceRing
+        from repro.obs import MetricsRegistry, TraceRing, Tracer
 
         if self.config.metrics is not None:
             self._metrics = MetricsRegistry() if self.config.metrics else None
         else:
             self._metrics = MetricsRegistry.from_env()
+        tracer = None
         if self._metrics is not None:
-            self._h_dispatch = self._metrics.histogram("serve.update_dispatch_ns")
-            self._h_publish = self._metrics.histogram("serve.publish_ns")
             self.trace = TraceRing()
-            self._trace_worker = os.environ.get("REPRO_FAULTS_WORKER")
-            if hasattr(self.source, "set_metrics"):
-                self.source.set_metrics(self._metrics)
+            worker = os.environ.get("REPRO_FAULTS_WORKER")
+            tracer = Tracer(
+                self._metrics, self.trace, **({"worker": worker} if worker else {})
+            )
+            self._sp_wait = tracer.site("serve.wait", "serve.wait_ns")
+            self._sp_update = tracer.site("update", "serve.update_dispatch_ns")
+            self._sp_h2d = tracer.site("serve.h2d", "serve.h2d_ns")
+            self._sp_publish = tracer.site("publish", "serve.publish_ns")
+            if hasattr(self.source, "set_tracer"):
+                self.source.set_tracer(tracer)
             session._obs = self._metrics.histogram("session.view_build_ns")
         else:
-            self._h_dispatch = self._h_publish = None
             self.trace = None
-            self._trace_worker = None
+            self._sp_wait = self._sp_update = self._sp_h2d = self._sp_publish = None
             session._obs = None  # a prior metrics-on serve must not linger
         if (
             self.config.max_batch is not None
@@ -133,7 +139,7 @@ class D4MServer:
             backpressure=self.config.backpressure,
             zero=session.sr.zero,
             val_dtype=np.dtype(session.dtype),
-            metrics=self._metrics,
+            tracer=tracer,
         )
         # the online query plane (ServeConfig.publish_every): an immutable
         # StreamView is published at microbatch boundaries; the source's
@@ -256,12 +262,13 @@ class D4MServer:
         in_flight = None  # popped batch not yet counted fed (error account)
         try:
             while True:
-                item = self.router.pop(timeout=self.config.poll_interval_s)
+                # batches_fed is the router's ordinal (batches_out) of the
+                # batch this wait ends with: the queue is FIFO, one consumer
+                sp = self._sp_wait
+                with (sp(batch=self.batches_fed) if sp is not None else NO_SPAN):
+                    item = self._next_item()
                 if item is DRAIN:
                     break
-                if item is None:
-                    self.router.flush_if_stale()
-                    continue
                 if self._abort.is_set():
                     self.records_discarded += int(item[3])
                     continue  # keep popping so a blocked producer unwinds
@@ -276,17 +283,10 @@ class D4MServer:
                         # fills behind us and the backpressure policy
                         # (block/drop) engages upstream
                         time.sleep(float(spec.args.get("seconds", 0.05)))
-                if self._h_dispatch is None:
+                sp = self._sp_update
+                span = sp(batch=self.batches_fed, live=int(live)) if sp is not None else NO_SPAN
+                with span:
                     self._dispatch(rows, cols, vals)
-                else:
-                    t0 = time.perf_counter_ns()
-                    self._dispatch(rows, cols, vals)
-                    t1 = time.perf_counter_ns()
-                    self._h_dispatch.record(t1 - t0)
-                    self.trace.append(
-                        "update", t0, t1, batch=int(live),
-                        worker=self._trace_worker,
-                    )
                 self.batches_fed += 1
                 self.records_fed += int(live)
                 in_flight = None
@@ -359,9 +359,20 @@ class D4MServer:
             self.session._serving = False
             self._done.set()
 
+    def _next_item(self):
+        """The next routed batch or :data:`DRAIN`, latency-flushing the
+        router's partial batch whenever a poll times out."""
+        while True:
+            item = self.router.pop(timeout=self.config.poll_interval_s)
+            if item is not None:
+                return item
+            self.router.flush_if_stale()
+
     def _dispatch(self, rows, cols, vals) -> None:
         s = self.session
-        rows, cols, vals = jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals)
+        sp = self._sp_h2d
+        with (sp(batch=self.batches_fed) if sp is not None else NO_SPAN):
+            rows, cols, vals = jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals)
         if s.kind == "mesh":
             rows, cols, vals = s.shard_stream(rows, cols, vals)
         s.update(rows, cols, vals)
@@ -391,20 +402,10 @@ class D4MServer:
                 self.session.sr,
                 self.session.dtype,
             )
-        if self._h_publish is None:
+        sp = self._sp_publish
+        with (sp(records=int(self.records_fed)) if sp is not None else NO_SPAN):
             self.session.view(
                 cap, records=self.records_fed, degrees=degrees, publish=True
-            )
-        else:
-            t0 = time.perf_counter_ns()
-            self.session.view(
-                cap, records=self.records_fed, degrees=degrees, publish=True
-            )
-            t1 = time.perf_counter_ns()
-            self._h_publish.record(t1 - t0)
-            self.trace.append(
-                "publish", t0, t1, records=int(self.records_fed),
-                worker=self._trace_worker,
             )
         self.views_published += 1
 

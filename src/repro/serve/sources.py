@@ -38,6 +38,8 @@ from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
+from repro.obs import NO_SPAN
+
 from . import wire
 
 Chunk = Tuple[np.ndarray, np.ndarray, np.ndarray]
@@ -50,6 +52,7 @@ class Source:
         self._stop = threading.Event()
         self.records_out = 0  # records yielded so far
         self.malformed = 0  # records/lines that failed to parse (skipped)
+        self._sp_decode = None  # the wire.decode span site (set_tracer)
 
     def start(self) -> "Source":
         return self
@@ -64,24 +67,31 @@ class Source:
     def chunks(self) -> Iterator[Chunk]:  # pragma: no cover - interface
         raise NotImplementedError
 
-    def set_metrics(self, registry) -> None:
-        """Attach an observability registry (``repro.obs.MetricsRegistry``).
-
-        Only decoding sources pay anything: their ``_decode`` callable is
-        wrapped so every decode call lands in the ``wire.decode_ns``
-        histogram.  Called by the serve loop when metrics are on; with
-        ``registry=None`` (or on a non-decoding source) this is a no-op and
-        the bare decoder keeps running — the disabled path stays identical
-        to a build without the obs plane.
-        """
-        if registry is None or not hasattr(self, "_decode"):
-            return
-        record = registry.histogram("wire.decode_ns").record
-        self._decode = wire.timed_decoder(self._decode, record)
+    def set_tracer(self, tracer) -> None:
+        """Attach the serve loop's :class:`repro.obs.Tracer`: each decode
+        call of a decoding source becomes a ``wire.decode`` span (the
+        ``wire.decode_ns`` histogram, the trace ring, the profiler's
+        trace).  Called by the serve loop when metrics are on; otherwise,
+        and on a source that decodes nothing, :attr:`_sp_decode` stays
+        ``None`` and a decode costs one ``is not None`` check."""
+        if hasattr(self, "_decode"):
+            self._sp_decode = tracer.site("wire.decode", "wire.decode_ns")
 
     def _count(self, chunk: Chunk) -> Chunk:
         self.records_out += int(chunk[0].shape[0])
         return chunk
+
+
+def _concat(triples) -> Optional[Chunk]:
+    """One chunk of the decoded insert triples; ``None`` when they hold no
+    record."""
+    if len(triples) == 1:
+        chunk = triples[0]
+    elif triples:
+        chunk = tuple(np.concatenate([t[i] for t in triples]) for i in range(3))
+    else:
+        return None
+    return chunk if chunk[0].shape[0] else None
 
 
 # ---------------------------------------------------------------------------
@@ -118,7 +128,6 @@ class TCPSource(Source):
         self.port = int(port)
         self.encoding = encoding
         self._decode = wire.decoder_for(encoding)
-        self._decode_messages = wire.decode_messages
         self.linger = linger
         self.poll_s = float(poll_s)
         self.recv_bytes = int(recv_bytes)
@@ -142,17 +151,6 @@ class TCPSource(Source):
 
     def set_faults(self, faults) -> None:
         self._faults = faults
-
-    def set_metrics(self, registry) -> None:
-        """Both decode paths (insert-only shim AND the message decoder the
-        query plane uses) feed the same ``wire.decode_ns`` histogram."""
-        if registry is None:
-            return
-        super().set_metrics(registry)
-        record = registry.histogram("wire.decode_ns").record
-        self._decode_messages = wire.timed_decoder(
-            self._decode_messages, record
-        )
 
     def set_query_handler(self, handler) -> None:
         """Install the query plane: ``handler(QueryRequest) -> QueryReply``.
@@ -265,57 +263,45 @@ class TCPSource(Source):
         buf = buffers[conn]
         if final and self.encoding == "text" and buf and not buf.endswith(b"\n"):
             buf += b"\n"  # a last record without its newline is still a record
-        if self._query_handler is None:
-            # insert-only path: byte-identical to the pre-query-plane source
-            try:
-                (r, c, v), leftover, bad = self._decode(buf)
-            except ValueError:
-                self.malformed += 1
-                buffers[conn] = b""
-                return None, False
-            if final and leftover:
-                # a producer died mid-frame: the incomplete tail is lost —
-                # count it so the shortfall is diagnosable from telemetry
-                bad += 1
-                leftover = b""
-            self.malformed += bad
-            buffers[conn] = leftover
-            if r.shape[0] == 0:
-                return None, True
-            return self._count((r, c, v)), True
+        sp = self._sp_decode
         try:
-            messages, leftover, bad = self._decode_messages(buf, self.encoding)
+            # one span over both decode paths: the insert-only decoder (with
+            # no query handler, byte-identical to the pre-query-plane source)
+            # and the op-coded message decoder the query plane speaks
+            with (sp() if sp is not None else NO_SPAN) as span:
+                if self._query_handler is None:
+                    triples, leftover, bad = self._decode(buf)
+                    triples, messages = [triples], ()
+                else:
+                    messages, leftover, bad = wire.decode_messages(buf, self.encoding)
+                    triples = [p for kind, p in messages if kind == "insert"]
+                chunk = _concat(triples)
+                span.note(records=0 if chunk is None else int(chunk[0].shape[0]))
         except ValueError:
             self.malformed += 1
             buffers[conn] = b""
             return None, False
         if final and leftover:
+            # a producer died mid-frame: the incomplete tail is lost —
+            # count it so the shortfall is diagnosable from telemetry
             bad += 1
             leftover = b""
         self.malformed += bad
         buffers[conn] = leftover
         alive = True
-        triples = []
         for kind, payload in messages:
-            if kind == "insert":
-                triples.append(payload)
-            elif kind == "query":
+            if kind == "query":
                 self.queries_seen += 1
                 if not self._send(conn, wire.encode_reply(
                     self._answer(payload), self.encoding
                 )):
                     alive = False  # client gone mid-reply: drop it
-            else:
+            elif kind != "insert":
                 # a REPLY arriving at the server is protocol nonsense —
                 # framing-valid, so skip it like a mangled text line
                 self.malformed += 1
-        if not triples:
+        if chunk is None:
             return None, alive
-        chunk = (
-            np.concatenate([t[0] for t in triples]),
-            np.concatenate([t[1] for t in triples]),
-            np.concatenate([t[2] for t in triples]),
-        )
         return self._count(chunk), alive
 
     def _answer(self, request) -> "wire.QueryReply":
@@ -453,7 +439,10 @@ class FileTailSource(Source):
     def _parse(self, buf: bytes, final: bool) -> Optional[Chunk]:
         if final and self.encoding == "text" and buf and not buf.endswith(b"\n"):
             buf += b"\n"
-        (r, c, v), self._leftover, bad = self._decode(buf)
+        sp = self._sp_decode
+        with (sp() if sp is not None else NO_SPAN) as span:
+            (r, c, v), self._leftover, bad = self._decode(buf)
+            span.note(records=int(r.shape[0]))
         if final and self._leftover:
             bad += 1  # truncated final frame: counted, not silently dropped
             self._leftover = b""

@@ -705,23 +705,6 @@ def decoder_for(encoding: str):
     raise ValueError(f"encoding must be one of {ENCODINGS}, got {encoding!r}")
 
 
-def timed_decoder(decode, record_ns):
-    """Wrap any decode callable so each call's wall time (perf_counter_ns
-    delta) is fed to ``record_ns`` — how a source instruments its decode
-    path without the decoder itself knowing about metrics.  Only installed
-    when observability is on; the disabled path keeps the bare decoder."""
-    import time
-
-    def timed(*a, **kw):
-        t0 = time.perf_counter_ns()
-        try:
-            return decode(*a, **kw)
-        finally:
-            record_ns(time.perf_counter_ns() - t0)
-
-    return timed
-
-
 # ---------------------------------------------------------------------------
 # loopback client
 # ---------------------------------------------------------------------------

@@ -21,7 +21,7 @@ import pytest
 
 from repro import d4m, serve
 from repro.core.telemetry import TelemetrySnapshot
-from repro.obs import MetricsRegistry, hist as obs_hist
+from repro.obs import LatencyHistogram, MetricsRegistry, TraceRing, Tracer, hist as obs_hist
 from repro.serve import wire
 from repro.serve.query import QUERY_OPS
 
@@ -162,11 +162,12 @@ def test_metrics_scrape_matches_registry_bit_exact():
     report = server.report()
     assert report.telemetry["records_fed"] == n
 
-    # trace ring saw both stages, with batch/record annotations
+    # trace ring saw both stages, with batch ordinals and live counts
     stages = {e["stage"] for e in server.trace.events()}
     assert {"update", "publish"} <= stages
     upd = [e for e in server.trace.events() if e["stage"] == "update"]
-    assert all(e["batch"] > 0 for e in upd)
+    assert [e["batch"] for e in upd] == list(range(n // BATCH))
+    assert all(e["live"] > 0 for e in upd)
 
 
 def test_stats_reply_carries_staleness_and_query_latency():
@@ -198,6 +199,54 @@ def test_stats_reply_carries_staleness_and_query_latency():
         assert s["count"] >= 2
         assert set(lat) <= set(QUERY_OPS)
     assert server.join(timeout=600)
+
+
+# ---------------------------------------------------------------------------
+# spans: every host stage of the ingest path, joined by batch ordinal
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_ingest_spans_join_by_batch(k):
+    n_batches = 300
+    n = n_batches * BATCH
+    r, c, v = _records(seed=12, n=n)
+    sess = _session(k)
+    src = serve.TCPSource(port=0, encoding="binary", linger=False)
+    server = serve.D4MServer(
+        sess, src, _serve_config(metrics=True, publish_every=None)
+    ).start()
+    wire.send_triples("127.0.0.1", src.port, r, c, v, encoding="binary",
+                      chunk_records=1000)
+    assert server.join(timeout=600)
+    assert server.records_fed == n
+
+    events = server.trace.events()
+    assert server.trace.total == len(events)  # nothing evicted
+    by = {}
+    for e in events:
+        by.setdefault(e["stage"], []).append(e)
+    assert {"wire.decode", "router.route", "serve.wait", "update",
+            "serve.h2d"} <= set(by)
+    assert sum(e["records"] for e in by["wire.decode"]) == n
+    slots = BATCH if k == 1 else k * BATCH
+    assert all(e["slots"] == slots for e in by["router.route"])
+    assert sum(e["live"] for e in by["router.route"]) == n
+    assert sum(e["live"] for e in by["update"]) == n
+
+    def one(stage):
+        spans = {e["batch"]: e for e in by[stage]}
+        assert len(spans) == len(by[stage])
+        return spans
+
+    route, upd, h2d = one("router.route"), one("update"), one("serve.h2d")
+    wait = one("serve.wait")  # and one more, ended by the drain
+    assert sorted(route) == sorted(upd) == sorted(h2d) == list(range(n_batches))
+    assert sorted(wait) == list(range(n_batches + 1))
+    for b in range(n_batches):
+        assert route[b]["t1_ns"] <= upd[b]["t0_ns"]
+        assert wait[b]["t1_ns"] <= upd[b]["t0_ns"]
+        assert upd[b]["t0_ns"] <= h2d[b]["t0_ns"] <= h2d[b]["t1_ns"] <= upd[b]["t1_ns"]
+        assert upd[b]["live"] == route[b]["live"]
 
 
 # ---------------------------------------------------------------------------
@@ -260,6 +309,10 @@ def _poison(monkeypatch):
     for name in ("counter", "gauge", "histogram", "dump", "summaries",
                  "to_prometheus"):
         monkeypatch.setattr(MetricsRegistry, name, boom)
+    # nor may it resolve a span site, append a span or record a duration
+    monkeypatch.setattr(Tracer, "site", boom)
+    monkeypatch.setattr(TraceRing, "append", boom)
+    monkeypatch.setattr(LatencyHistogram, "record", boom)
 
 
 def test_disabled_path_never_touches_registry(monkeypatch):
