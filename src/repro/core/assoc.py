@@ -271,6 +271,78 @@ def run_fold(rows: jax.Array, cols: jax.Array, vals: jax.Array, add) -> jax.Arra
     return lax.fori_loop(0, _log_steps(rows.shape[0]), step, vals)
 
 
+def _lex_less(xs, ys) -> jax.Array:
+    """Strict lexicographic ``xs < ys`` over parallel key lanes."""
+    less = xs[-1] < ys[-1]
+    for x, y in zip(xs[-2::-1], ys[-2::-1]):
+        less = (x < y) | ((x == y) & less)
+    return less
+
+
+def looped_bitonic_merge(lanes, n_keys: int) -> tuple:
+    """Sort a bitonic sequence (non-increasing, then non-decreasing) into
+    ascending lexicographic order of its first ``n_keys`` lanes; the other
+    lanes travel with their keys.
+
+    The width need not be a power of two: the sequence counts as padded to
+    one with keys above every key, which never move, so a slot whose
+    partner lies past the end keeps its element.  ``ceil(log2 n)``
+    compare-exchange passes at halving distance ``d``: slot ``i`` meets
+    ``i + d`` when ``i & d`` is 0 (and keeps the smaller) and ``i - d``
+    otherwise (and keeps the larger), each partner one contiguous shift
+    away.  The passes run as one ``fori_loop`` body, so the program does
+    not grow with the width.
+    """
+    n = lanes[0].shape[0]
+    steps = _log_steps(n)
+    idx = jnp.arange(n, dtype=jnp.int32)
+    top = 1 << max(0, steps - 1)
+
+    def step(i, lanes):
+        d = jnp.right_shift(top, i)
+        upper = (idx & d) != 0
+        partner = [jnp.where(upper, jnp.roll(x, d), jnp.roll(x, -d)) for x in lanes]
+        own_k, par_k = lanes[:n_keys], partner[:n_keys]
+        take = jnp.where(
+            upper, _lex_less(own_k, par_k), _lex_less(par_k, own_k) & (idx + d < n)
+        )
+        return tuple(jnp.where(take, p, x) for p, x in zip(partner, lanes))
+
+    return lax.fori_loop(0, steps, step, tuple(lanes))
+
+
+def looped_compact(lanes, keep: jax.Array, fills) -> list:
+    """Stable compaction: the ``keep``-flagged elements of each lane move to
+    the prefix in their order, and ``fills`` fill the rest.
+
+    Each survivor travels left by the number of dead slots before it, a
+    distance that never falls along the array, so moving it by one bit of
+    that distance a pass, least significant first, never lands two
+    survivors on one slot.  ``ceil(log2 n)`` passes of one contiguous
+    shift and a select, as one ``fori_loop`` body; the distance rides
+    along as a lane, -1 on dead slots.
+    """
+    n = lanes[0].shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    dead = jnp.logical_not(keep).astype(jnp.int32)
+    dist = jnp.where(keep, prefix_sum(dead) - dead, -1)
+    lanes = tuple(jnp.where(keep, x, f) for x, f in zip(lanes, fills))
+
+    def step(i, carry):
+        dist, lanes = carry
+        d = jnp.left_shift(1, i)
+        stay = (dist >= 0) & ((dist & d) == 0)
+        dist_in = jnp.where(idx + d < n, jnp.roll(dist, -d), -1)
+        arrive = (dist_in >= 0) & ((dist_in & d) != 0)
+        lanes = tuple(
+            jnp.where(arrive, jnp.roll(x, -d), jnp.where(stay, x, f))
+            for x, f in zip(lanes, fills)
+        )
+        return jnp.where(arrive, dist_in, jnp.where(stay, dist, -1)), lanes
+
+    return list(lax.fori_loop(0, _log_steps(n), step, (dist, lanes))[1])
+
+
 def _combine_sorted(
     rows: jax.Array, cols: jax.Array, vals: jax.Array, cap: int, sr: Semiring
 ) -> Assoc:
@@ -368,34 +440,54 @@ def _head(x: Assoc, cap: int) -> Assoc:
 def add(a: Assoc, b: Assoc, cap: int | None = None, sr: Semiring = PLUS_TIMES) -> Assoc:
     """``C = A (+) B`` — element-wise semiring addition (table union).
 
-    Both inputs are sorted, so we merge by rank (two lex-searchsorted passes)
-    rather than re-sorting the concatenation: O((m+n) log(m+n)) comparisons
-    with a small constant, and the exact algorithm the Pallas ``merge_add``
-    kernel implements in VMEM tiles on TPU.  An input wider than ``cap``
-    enters by its first ``cap`` slots (:func:`_head`), so the cost follows
-    ``cap``: a snapshot of a 151M-slot top layer at ``cap`` 2M merges 2M.
+    Both inputs are sorted, so reversed A followed by B is a bitonic
+    sequence: :func:`looped_bitonic_merge` sorts it in ``ceil(log2(m+n))``
+    compare-exchange passes, each key shared by A and B lands as an
+    adjacent pair that one pass folds, and :func:`looped_compact` closes
+    the gaps.  Every element reaches its place through contiguous shifts
+    and selects: no gather, scatter or sort, so the TPU streams each pass
+    through HBM at any width.  A semiring whose ``add`` does not commute
+    carries a source lane that puts A's copy of a shared key first.  An
+    input wider than ``cap`` enters by its first ``cap`` slots
+    (:func:`_head`), so the cost follows ``cap``: a snapshot of a 151M-slot
+    top layer at ``cap`` 2M merges 2M.
     """
     if cap is None:
         cap = a.capacity + b.capacity
     a, b = _head(a, cap), _head(b, cap)
     m, n = a.capacity, b.capacity
     total = m + n
-    # merge-by-rank: stable positions for A's and B's elements in the merge.
-    pos_a = jnp.arange(m, dtype=jnp.int32) + lex_searchsorted(
-        b.rows, b.cols, a.rows, a.cols, side="left"
+    # descending (A reversed, its PAD tail first) then ascending (B)
+    keys = [jnp.concatenate([x[::-1], y]) for x, y in ((a.rows, b.rows), (a.cols, b.cols))]
+    if not sr.add_commutes:
+        keys.append(jnp.concatenate([jnp.zeros((m,), jnp.int32), jnp.ones((n,), jnp.int32)]))
+    vals = jnp.concatenate([a.vals[::-1], b.vals])
+    *keys, vals = looped_bitonic_merge([*keys, vals], len(keys))
+    rows, cols = keys[:2]
+    # keys are unique in each input, so a run of equal keys is at most a pair
+    idx = jnp.arange(total, dtype=jnp.int32)
+    prev_same = (rows == jnp.roll(rows, 1)) & (cols == jnp.roll(cols, 1)) & (idx > 0)
+    next_same = (
+        (rows == jnp.roll(rows, -1)) & (cols == jnp.roll(cols, -1)) & (idx < total - 1)
     )
-    pos_b = jnp.arange(n, dtype=jnp.int32) + lex_searchsorted(
-        a.rows, a.cols, b.rows, b.cols, side="right"
-    )
-    # the two rank vectors are a permutation of [0, total): one scatter per
-    # lane (XLA's TPU compile time grows with each scatter's width)
-    pos = jnp.concatenate([pos_a, pos_b])
-    rows = jnp.zeros((total,), jnp.int32).at[pos].set(jnp.concatenate([a.rows, b.rows]))
-    cols = jnp.zeros((total,), jnp.int32).at[pos].set(jnp.concatenate([a.cols, b.cols]))
-    vals = jnp.zeros((total,), a.vals.dtype).at[pos].set(jnp.concatenate([a.vals, b.vals]))
-    out = _combine_sorted(rows, cols, vals, cap, sr)
-    return dataclasses.replace(
-        out, overflow=out.overflow | a.overflow | b.overflow
+    vals = jnp.where(prev_same, sr.add(jnp.roll(vals, 1), vals), vals)
+    keep = jnp.logical_not(next_same) & (rows != PAD)
+    n_keep = keep.sum(dtype=jnp.int32)
+    fills = (PAD, PAD, jnp.asarray(sr.zero, vals.dtype))
+    out = looped_compact((rows, cols, vals), keep, fills)
+    if total >= cap:
+        out = [x[:cap] for x in out]
+    else:
+        out = [
+            jnp.concatenate([x, jnp.full((cap - total,), f, x.dtype)])
+            for x, f in zip(out, fills)
+        ]
+    return Assoc(
+        rows=out[0],
+        cols=out[1],
+        vals=out[2],
+        nnz=jnp.minimum(n_keep, cap),
+        overflow=(n_keep > cap) | a.overflow | b.overflow,
     )
 
 

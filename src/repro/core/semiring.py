@@ -23,6 +23,9 @@ class Semiring:
     ``add``/``mul`` must be elementwise-broadcastable jnp functions.
     ``zero`` is the additive identity *and* multiplicative annihilator —
     it is also used as the padding value for dead slots in an Assoc.
+    ``add_commutes`` is False only for an ``add`` whose result depends on
+    the order of its operands (``union.first``): ``assoc.add`` then keeps
+    the left operand's entry first for each shared key.
     """
 
     name: str
@@ -30,6 +33,7 @@ class Semiring:
     mul: Callable
     zero: float
     one: float
+    add_commutes: bool = True
 
     def __repr__(self) -> str:  # pragma: no cover - cosmetic
         return f"Semiring({self.name})"
@@ -71,7 +75,7 @@ MIN_TIMES = Semiring("min.times", _min, _times, jnp.inf, 1.0)  # V = [0, inf]
 MAX_MIN = Semiring("max.min", _max, _min, 0.0, jnp.inf)  # V = [0, inf]
 MIN_MAX = Semiring("min.max", _min, _max, jnp.inf, 0.0)  # V = [0, inf]
 # Union/intersection analogue on numeric labels: "keep first" fold.
-FIRST = Semiring("union.first", _first, _second, jnp.nan, jnp.nan)
+FIRST = Semiring("union.first", _first, _second, jnp.nan, jnp.nan, add_commutes=False)
 # Counting semiring: add = +, mul = logical AND-ish product of counts.
 COUNT = Semiring("count", _plus, _times, 0.0, 1.0)
 

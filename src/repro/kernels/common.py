@@ -2,8 +2,8 @@
 networks (bitonic sort / bitonic merge) over (row, col, payload...) lanes.
 
 Why bitonic networks on TPU: the CPU implementation of D4M merges sorted
-triple lists with data-dependent pointer chasing, and XLA lowers the jnp
-merge-by-rank fallback to *scatter* — both hostile to the TPU's vector unit.
+triple lists with data-dependent pointer chasing, and a merge by rank lowers
+to gathers and scatters — both hostile to the TPU's vector unit.
 A bitonic network is oblivious: a fixed sequence of strided compare-exchange
 passes, each expressible as a reshape + vectorized select over VMEM-resident
 lanes.  No gathers, no scatters, no data-dependent control flow.

@@ -6,7 +6,7 @@ LM training: an embedding-gradient microbatch is a hypersparse update stream
 ``token_id -> grad_row`` (a few thousand distinct ids out of a 32 K-262 K
 vocab).  The structure below is exactly ``repro.core.assoc`` with
 ``(row=token_id, col=0)`` keys and vector payloads — same sorted-key layout,
-same rank-merge, same segmented combine, same capacity discipline.
+same segmented combine, same capacity discipline.
 """
 from __future__ import annotations
 
@@ -81,8 +81,7 @@ def from_pairs(ids: jax.Array, rows: jax.Array, cap: int) -> RowAccum:
 
 
 def merge(a: RowAccum, b: RowAccum, cap: int | None = None) -> RowAccum:
-    """``A (+) B`` by rank-merge (both inputs sorted) — the merge_add
-    algorithm with a row payload."""
+    """``A (+) B`` by rank-merge (both inputs sorted), with a row payload."""
     if cap is None:
         cap = a.capacity + b.capacity
     m, n = a.capacity, b.capacity

@@ -208,3 +208,106 @@ def test_add_into_narrower_cap_matches_full_merge(cap):
         np.testing.assert_array_equal(np.asarray(getattr(got, f)), want)
     assert int(got.nnz) == min(n_full, cap)
     assert bool(got.overflow) == (n_full > cap)
+
+
+# ---------------------------------------------------------------------------
+# add against the rank merge it replaced
+# ---------------------------------------------------------------------------
+
+def _rank_merge_add(a, b, cap, sr=semiring.PLUS_TIMES):
+    """Reference: merge by rank (two lex-searchsorted passes, one scatter per
+    lane), fold runs and compact; A's copy of a shared key comes first."""
+    a, b = assoc._head(a, cap), assoc._head(b, cap)
+    m, n = a.capacity, b.capacity
+    pos = jnp.concatenate([
+        jnp.arange(m, dtype=jnp.int32)
+        + assoc.lex_searchsorted(b.rows, b.cols, a.rows, a.cols, side="left"),
+        jnp.arange(n, dtype=jnp.int32)
+        + assoc.lex_searchsorted(a.rows, a.cols, b.rows, b.cols, side="right"),
+    ])
+    lanes = [
+        jnp.zeros((m + n,), x.dtype).at[pos].set(jnp.concatenate([x, y]))
+        for x, y in ((a.rows, b.rows), (a.cols, b.cols), (a.vals, b.vals))
+    ]
+    out = assoc._combine_sorted(*lanes, cap, sr)
+    return assoc.Assoc(
+        rows=out.rows, cols=out.cols, vals=out.vals, nnz=out.nnz,
+        overflow=out.overflow | a.overflow | b.overflow,
+    )
+
+
+def _sorted_assoc(keys, cap, rng, sr=semiring.PLUS_TIMES, overflow=False):
+    """An Assoc of capacity ``cap`` holding the unique ``keys`` (code
+    ``row * 64 + col``) with random values, built without a device sort."""
+    keys = np.unique(np.asarray(keys, np.int64))
+    rows = np.full(cap, PAD, np.int32)
+    cols = np.full(cap, PAD, np.int32)
+    vals = np.full(cap, sr.zero, np.float32)
+    rows[: keys.size], cols[: keys.size] = keys // 64, keys % 64
+    vals[: keys.size] = rng.normal(size=keys.size)
+    return assoc.Assoc(
+        rows=jnp.asarray(rows), cols=jnp.asarray(cols), vals=jnp.asarray(vals),
+        nnz=jnp.asarray(keys.size, jnp.int32), overflow=jnp.asarray(overflow),
+    )
+
+
+# (m, n, cap, live keys in A, in B, shared, semiring, inputs flagged)
+_ADD_CASES = {
+    "non_pow2_widths": (45, 39, 84, 30, 25, 10, "plus.times", False),
+    "empty_a": (16, 37, 53, 0, 37, 0, "plus.times", False),
+    "empty_b": (37, 16, 53, 37, 0, 0, "max.plus", False),
+    "all_keys_shared": (40, 40, 80, 33, 33, 33, "plus.times", False),
+    "no_keys_shared": (33, 31, 64, 33, 31, 0, "min.plus", False),
+    "cap_below_total": (48, 40, 21, 40, 30, 5, "plus.times", False),
+    "cap_above_total": (20, 9, 40, 17, 9, 4, "plus.times", False),
+    "inputs_flagged_overflow": (24, 24, 48, 20, 20, 8, "plus.times", True),
+    "order_sensitive_add": (45, 39, 84, 30, 25, 20, "union.first", False),
+}
+
+
+def _add_case_inputs(name, seed):
+    m, n, cap, na, nb, shared, srn, flagged = _ADD_CASES[name]
+    rng = np.random.default_rng(seed)
+    space = rng.permutation(64 * 64)
+    ka = space[:na]
+    kb = np.concatenate([ka[:shared], space[na : na + nb - shared]])
+    sr = semiring.get(srn)
+    a = _sorted_assoc(ka, m, rng, sr, overflow=flagged)
+    b = _sorted_assoc(kb, n, rng, sr)
+    return a, b, cap, sr
+
+
+def _assert_same_assoc(got, want):
+    for f in ("rows", "cols", "vals", "nnz", "overflow"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, f)), np.asarray(getattr(want, f)), err_msg=f
+        )
+
+
+@pytest.mark.parametrize("name", [*_ADD_CASES, "vmap"])
+def test_add_bit_exact_against_rank_merge(name):
+    """The bitonic merge returns what the rank merge returned, bit for bit:
+    entries, values, nnz and the overflow flag."""
+    if name == "vmap":
+        ins = [_add_case_inputs("non_pow2_widths", s) for s in range(3)]
+        a = jax.tree.map(lambda *x: jnp.stack(x), *[i[0] for i in ins])
+        b = jax.tree.map(lambda *x: jnp.stack(x), *[i[1] for i in ins])
+        cap = ins[0][2]
+        got = jax.vmap(lambda a, b: assoc.add(a, b, cap=cap))(a, b)
+        want = jax.vmap(lambda a, b: _rank_merge_add(a, b, cap))(a, b)
+    else:
+        a, b, cap, sr = _add_case_inputs(name, 0)
+        got = jax.jit(lambda a, b: assoc.add(a, b, cap=cap, sr=sr))(a, b)
+        want = jax.jit(lambda a, b: _rank_merge_add(a, b, cap, sr))(a, b)
+    _assert_same_assoc(got, want)
+    if name == "cap_below_total":
+        assert bool(got.overflow) and int(got.nnz) == cap
+    if name == "inputs_flagged_overflow":
+        assert bool(got.overflow)
+
+
+def test_add_lowers_without_gather_scatter_or_sort():
+    a, b, cap, sr = _add_case_inputs("non_pow2_widths", 0)
+    text = jax.jit(lambda a, b: assoc.add(a, b, cap=cap, sr=sr)).lower(a, b).as_text()
+    for op in ("gather", "scatter", "sort"):
+        assert f"stablehlo.{op}" not in text, op
